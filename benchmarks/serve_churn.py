@@ -106,6 +106,7 @@ from repro.models import TransformerLM
 from repro.serve import ServeConfig
 from repro.serve.router import LaneSpec, SLO_CLASSES, ttft_attainment
 from repro.serve.telemetry import Telemetry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import run_continuous
 
 
@@ -410,6 +411,7 @@ def run(budget=None, *, arch="qwen2-1.5b", mux_n=2, rows=2,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny trace (CI / laptop CPU)")

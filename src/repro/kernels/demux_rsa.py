@@ -11,7 +11,10 @@ is a (N, F) matrix precomputed outside (negligible).
 
 Grid: (N, T/bt, F/bf); F is the innermost (sequential on TPU) axis so the
 output tile accumulates in place across F steps.  MXU-aligned tiles
-(bt, bf multiples of 128).
+(bt, bf multiples of 128).  The default tiles keep the double-buffered
+h, W1h, W2 and output tiles of an fp32 D=1536 model (qwen2-1.5b) inside
+the 16 MiB of VMEM a v5e kernel may use by default; the per-instance
+bias carries a unit axis so its (1, bf) block is a whole minor tile.
 
 Epilogue fusion (the serve decode exit path): ``entry_kind`` absorbs the
 backbone's final norm (RMS or LN) into the kernel's read of h, and
@@ -48,7 +51,7 @@ def _entry_norm(h, kind, scale_ref, bias_ref):
 
 def _kernel_full(h_ref, w1h_ref, kb_ref, w2_ref, b2_ref, *rest,
                  f_last: int, entry_kind, exit_ln: bool):
-    # h_ref: (bt, D); w1h_ref: (D, bf); kb_ref: (1, bf) [b1 folded in];
+    # h_ref: (bt, D); w1h_ref: (D, bf); kb_ref: (1, 1, bf) [b1 folded in];
     # w2_ref: (bf, D); b2_ref: (1, D); o_ref: (1, bt, D) accumulated
     # across the (sequential, innermost) F grid axis.  Optional norm
     # params ride between b2 and the output ref.
@@ -88,7 +91,7 @@ def _kernel_full(h_ref, w1h_ref, kb_ref, w2_ref, b2_ref, *rest,
                                              "block_f", "interpret"))
 def demux_rsa(h, k, w1h, w1k, b1, w2, b2, *, entry_kind=None,
               entry_scale=None, entry_bias=None, exit_scale=None,
-              exit_bias=None, block_t: int = 256, block_f: int = 512,
+              exit_bias=None, block_t: int = 128, block_f: int = 256,
               interpret: bool = False):
     """h: (T, D); k: (N, D); w1h: (D, F); w1k: (D, F); b1: (F,);
     w2: (F, D); b2: (D,) -> (N, T, D).
@@ -117,11 +120,11 @@ def demux_rsa(h, k, w1h, w1k, b1, w2, b2, *, entry_kind=None,
     in_specs = [
         pl.BlockSpec((bt, d), lambda i, j, l: (j, 0)),     # h rows
         pl.BlockSpec((d, bf), lambda i, j, l: (0, l)),     # W1h F-tile
-        pl.BlockSpec((1, bf), lambda i, j, l: (i, l)),     # k@W1k+b1
+        pl.BlockSpec((1, 1, bf), lambda i, j, l: (i, 0, l)),  # k@W1k+b1
         pl.BlockSpec((bf, d), lambda i, j, l: (l, 0)),     # W2 F-tile
         pl.BlockSpec((1, d), lambda i, j, l: (0, 0)),      # b2
     ]
-    args = [h, w1h, kb, w2, b2[None]]
+    args = [h, w1h, kb[:, None], w2, b2[None]]
     row_spec = pl.BlockSpec((1, d), lambda i, j, l: (0, 0))
     if entry_kind is not None:
         in_specs.append(row_spec)

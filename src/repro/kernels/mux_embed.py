@@ -15,6 +15,12 @@ Grid: (T, D/bd, i) with the instance axis innermost (sequential on TPU)
 so the accumulator carries across instances of one (t, d-tile).
 ``scale`` folds the backbone's static embedding scale (sqrt(D)) into the
 epilogue for free.
+
+Mosaic tiles the last two dims of every block by (8, 128) unless a dim
+is whole, so a single-row block is not expressible: each step DMAs the
+8-row group holding the token's row (``ROWS``) and picks the row in
+VMEM, the keys come in whole and are picked the same way, and the output
+carries a unit axis so its (1, bd) block is a whole minor tile.
 """
 from __future__ import annotations
 
@@ -26,15 +32,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+ROWS = 8      # embedding rows per DMA: one sublane tile
+
+
 def _kernel(tok_ref, e_ref, v_ref, o_ref, acc_ref, *, n: int, scale: float):
+    ti = pl.program_id(0)
     ni = pl.program_id(2)
 
     @pl.when(ni == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += (e_ref[0].astype(jnp.float32)
-                     * v_ref[0].astype(jnp.float32))
+    e = e_ref[pl.ds(tok_ref[ni, ti] % ROWS, 1), :]       # (1, bd)
+    acc_ref[...] += (e.astype(jnp.float32)
+                     * v_ref[pl.ds(ni, 1), :].astype(jnp.float32))
 
     @pl.when(ni == n - 1)
     def _fin():
@@ -59,15 +70,17 @@ def mux_embed_combine(tokens, emb, v, *, scale: float = 1.0,
         num_scalar_prefetch=1,                     # tokens
         grid=(t, pl.cdiv(d, bd), n),
         in_specs=[
-            pl.BlockSpec((1, bd), lambda t_, j, i, tok: (tok[i, t_], j)),
-            pl.BlockSpec((1, bd), lambda t_, j, i, tok: (i, j)),
+            pl.BlockSpec((ROWS, bd),
+                         lambda t_, j, i, tok: (tok[i, t_] // ROWS, j)),
+            pl.BlockSpec((n, bd), lambda t_, j, i, tok: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bd), lambda t_, j, i, tok: (t_, j)),
-        scratch_shapes=[pltpu.VMEM((bd,), jnp.float32)],
+        out_specs=pl.BlockSpec((1, 1, bd), lambda t_, j, i, tok: (t_, 0, j)),
+        scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, n=n, scale=float(scale)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, d), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((t, 1, d), out_dtype),
         interpret=interpret,
     )(tokens, emb, v)
+    return out.reshape(t, d)

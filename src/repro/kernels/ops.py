@@ -1,9 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container kernels execute in interpret mode (the kernel body
-runs in Python via the Pallas interpreter — bitwise the same program the
-Mosaic compiler would lower for TPU); on a TPU runtime ``interpret=False``
-compiles to Mosaic.
+On a TPU the kernels compile with Mosaic.  On the CPU they run in
+interpret mode (the kernel body runs through the Pallas interpreter — the
+same program, for tests and development).  Any other backend is an
+error: no kernel silently runs interpreted on an accelerator.
 """
 from __future__ import annotations
 
@@ -19,7 +19,11 @@ from repro.kernels import paged_attention as _paged
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"the Pallas kernels target TPU (Mosaic) or "
+                           f"the CPU interpreter, not {backend!r}")
+    return backend == "cpu"
 
 
 def mux_combine(x, v, **kw):

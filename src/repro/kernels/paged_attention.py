@@ -28,16 +28,16 @@ entries).  Queries past a row's valid length (bucket padding) are fully
 masked and produce discarded output.
 
 ``sharded_paged_attention`` / ``sharded_paged_prefill_attention`` run
-the same kernels under ``shard_map`` over a mesh's 'data' axis: rows and
-the pool's blocks axis partition per shard, global block ids are rebased
-to the shard's local page segment (the ``ShardedKVPool`` row->shard
-invariant guarantees a shard's tables only reference its own segment),
-and each shard's kernel issues page DMAs only against resident pages —
-the decode path needs NO collectives (DESIGN.md §sharded serving).
-Both require the row batch to split evenly over 'data'; the serve
-runtime's decode grid always does, while its one-row prefill chunks do
-not (a single joining row lives on one shard) and fall back to the
-GSPMD-partitioned path — see the guard in ``models.blocks``.
+the same kernels under ``shard_map`` over a mesh's 'data' axis: the
+pool's blocks axis partitions per shard, global block ids are rebased
+to the shard's local page segment, and each shard's kernel issues page
+DMAs only against resident pages.  Rows partition too where they split
+evenly over 'data' (the ``ShardedKVPool`` row->shard invariant makes
+that exact) — the decode grid, which needs NO collectives (DESIGN.md
+§sharded serving).  A one-row prefill chunk lives on one shard, so its
+rows replicate and a psum keeps the owning shard's output.  Mosaic
+kernels cannot be partitioned by GSPMD, so on a mesh these wrappers are
+the only way the paged kernels run.
 """
 from __future__ import annotations
 
@@ -73,9 +73,9 @@ def _kernel(bt_ref, qp_ref, q_ref, k_ref, v_ref, *rest, mb: int, window,
         # fused dequant: int8/fp8 page payload × per-slot fp32 scale,
         # right on the VMEM copy the DMA just landed — high-precision
         # K/V never exists outside the kernel
-        k = k * ks_ref[0, 0][:, None]              # (bs,) scales
-        v = v * vs_ref[0, 0][:, None]
-    pos = pos_ref[0]                               # (bs,) slot positions
+        k = k * ks_ref[0, 0, 0][:, None]           # (bs,) scales
+        v = v * vs_ref[0, 0, 0][:, None]
+    pos = pos_ref[0, 0]                            # (bs,) slot positions
     dh = q.shape[-1]
     q_pos = qp_ref[bi]
 
@@ -101,6 +101,33 @@ def _kernel(bt_ref, qp_ref, q_ref, k_ref, v_ref, *rest, mb: int, window,
             l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
 
 
+def _pool_operands(k_pages, v_pages, page_pos, k_scales, v_scales):
+    """BlockSpecs + operands for the pool side of both paged kernels,
+    addressed through the scalar-prefetched block table (the first
+    prefetch ref).  Mosaic tiles the last two dims of every block, so
+    each operand is laid out such that those dims are either whole or
+    (8, 128)-aligned: pages go head-major (P, Hkv, BS, dh), and the
+    per-slot vectors — slot positions and quantization scales — carry a
+    unit axis so that a (1, bs) slice is a whole (1, BS) minor tile."""
+    p, bs, hkv = k_pages.shape[:3]
+
+    def page(b_, h_, j, bt, *_):
+        return (jnp.maximum(bt[b_, j], 0), h_, 0, 0)
+
+    def slot_pos(b_, h_, j, bt, *_):
+        return (jnp.maximum(bt[b_, j], 0), 0, 0)
+
+    specs = [pl.BlockSpec((1, 1, bs, k_pages.shape[3]), page)] * 2
+    args = [k_pages.transpose(0, 2, 1, 3), v_pages.transpose(0, 2, 1, 3)]
+    if k_scales is not None:
+        specs += [pl.BlockSpec((1, 1, 1, bs), page)] * 2
+        args += [s.transpose(0, 2, 1).reshape(p, hkv, 1, bs)
+                 for s in (k_scales, v_scales)]
+    specs.append(pl.BlockSpec((1, 1, bs), slot_pos))
+    args.append(page_pos.reshape(p, 1, bs))
+    return specs, args
+
+
 @functools.partial(jax.jit, static_argnames=("window", "causal", "interpret"))
 def paged_attention(q, k_pages, v_pages, block_tables, page_pos, q_pos, *,
                     k_scales=None, v_scales=None, window=None,
@@ -122,35 +149,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, page_pos, q_pos, *,
     quantized = k_scales is not None
 
     qt = q.reshape(b, hkv, g, dh)                  # group queries per kv head
-    kt = k_pages.transpose(0, 2, 1, 3)             # (P, Hkv, BS, dh)
-    vt = v_pages.transpose(0, 2, 1, 3)
-
-    def page_map(b_, h_, j, bt, qp):
-        return (jnp.maximum(bt[b_, j], 0), h_, 0, 0)
-
-    def scale_map(b_, h_, j, bt, qp):
-        return (jnp.maximum(bt[b_, j], 0), h_, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, g, dh), lambda b_, h_, j, bt, qp: (b_, h_, 0, 0)),
-        pl.BlockSpec((1, 1, bs, dh), page_map),
-        pl.BlockSpec((1, 1, bs, dh), page_map),
-    ]
-    args = [qt, kt, vt]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, 1, bs), scale_map),
-                     pl.BlockSpec((1, 1, bs), scale_map)]
-        args += [k_scales.transpose(0, 2, 1),      # (P, Hkv, BS)
-                 v_scales.transpose(0, 2, 1)]
-    in_specs.append(
-        pl.BlockSpec((1, bs),
-                     lambda b_, h_, j, bt, qp: (jnp.maximum(bt[b_, j], 0), 0)))
-    args.append(page_pos)
+    pool_specs, pool_args = _pool_operands(k_pages, v_pages, page_pos,
+                                           k_scales, v_scales)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                     # block_tables, q_pos
         grid=(b, hkv, mb),
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((1, 1, g, dh),
+                               lambda b_, h_, j, bt, qp: (b_, h_, 0, 0)),
+                  *pool_specs],
         out_specs=pl.BlockSpec((1, 1, g, dh),
                                lambda b_, h_, j, bt, qp: (b_, h_, 0, 0)),
         scratch_shapes=[
@@ -165,7 +172,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, page_pos, q_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
         interpret=interpret,
-    )(block_tables, q_pos, *args)
+    )(block_tables, q_pos, qt, *pool_args)
     return out.reshape(b, 1, h, dh)
 
 
@@ -189,9 +196,9 @@ def _prefill_kernel(bt_ref, qs_ref, ql_ref, q_ref, k_ref, v_ref, *rest,
     k = k_ref[0, 0].astype(jnp.float32)            # (bs, dh) one page
     v = v_ref[0, 0].astype(jnp.float32)
     if quantized:
-        k = k * ks_ref[0, 0][:, None]              # fused dequant (bs,)
-        v = v * vs_ref[0, 0][:, None]
-    pos = pos_ref[0]                               # (bs,) slot positions
+        k = k * ks_ref[0, 0, 0][:, None]           # fused dequant (bs,)
+        v = v * vs_ref[0, 0, 0][:, None]
+    pos = pos_ref[0, 0]                            # (bs,) slot positions
     dh = q.shape[-1]
     bs = k.shape[0]
 
@@ -255,37 +262,15 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, page_pos,
     # mask broadcasts over groups with one reshape
     qt = q.reshape(b, lq, hkv, g, dh).transpose(0, 2, 3, 1, 4)
     qt = qt.reshape(b, hkv, g * lq, dh)
-    kt = k_pages.transpose(0, 2, 1, 3)             # (P, Hkv, BS, dh)
-    vt = v_pages.transpose(0, 2, 1, 3)
-
-    def page_map(b_, h_, j, bt, qs, ql):
-        return (jnp.maximum(bt[b_, j], 0), h_, 0, 0)
-
-    def scale_map(b_, h_, j, bt, qs, ql):
-        return (jnp.maximum(bt[b_, j], 0), h_, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, g * lq, dh),
-                     lambda b_, h_, j, bt, qs, ql: (b_, h_, 0, 0)),
-        pl.BlockSpec((1, 1, bs, dh), page_map),
-        pl.BlockSpec((1, 1, bs, dh), page_map),
-    ]
-    args = [qt, kt, vt]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, 1, bs), scale_map),
-                     pl.BlockSpec((1, 1, bs), scale_map)]
-        args += [k_scales.transpose(0, 2, 1),      # (P, Hkv, BS)
-                 v_scales.transpose(0, 2, 1)]
-    in_specs.append(
-        pl.BlockSpec((1, bs),
-                     lambda b_, h_, j, bt, qs, ql:
-                     (jnp.maximum(bt[b_, j], 0), 0)))
-    args.append(page_pos)
+    pool_specs, pool_args = _pool_operands(k_pages, v_pages, page_pos,
+                                           k_scales, v_scales)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,                     # bt, q_start, q_len
         grid=(b, hkv, mb),
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((1, 1, g * lq, dh),
+                               lambda b_, h_, j, bt, qs, ql: (b_, h_, 0, 0)),
+                  *pool_specs],
         out_specs=pl.BlockSpec((1, 1, g * lq, dh),
                                lambda b_, h_, j, bt, qs, ql: (b_, h_, 0, 0)),
         scratch_shapes=[
@@ -300,7 +285,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, page_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g * lq, dh), q.dtype),
         interpret=interpret,
-    )(block_tables, q_start, q_len, *args)
+    )(block_tables, q_start, q_len, qt, *pool_args)
     return out.reshape(b, hkv, g, lq, dh).transpose(0, 3, 1, 2, 4) \
               .reshape(b, lq, h, dh)
 
@@ -308,14 +293,6 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, page_pos,
 # ===========================================================================
 # shard_map wrappers: shard-local kernels over a (data, ...) mesh
 # ===========================================================================
-
-def _local_tables(bt, axis: str, blocks_per_shard: int):
-    """Rebase a shard's slice of the global block table to its local page
-    segment: shard s owns global ids [s*bps, (s+1)*bps) (the ShardedKVPool
-    convention), so local id = global - s*bps; -1 stays -1."""
-    off = jax.lax.axis_index(axis) * blocks_per_shard
-    return jnp.where(bt >= 0, bt - off, -1)
-
 
 def _head_axis(mesh, h: int, hkv: int):
     """Tensor-parallel head split inside the shard_map: only when BOTH
@@ -326,65 +303,69 @@ def _head_axis(mesh, h: int, hkv: int):
     return "model" if m > 1 and h % m == 0 and hkv % m == 0 else None
 
 
-def _specs(mesh, axis: str, head):
-    """(q, kv-pages, bt, scalar-vector) PartitionSpecs: rows/blocks over
-    ``axis``, the head dims (q axis 2, page axis 2) over ``head``."""
+def _shard_mapped(kernel, mesh, q, k_pages, v_pages, block_tables,
+                  page_pos, row_vecs, *, k_scales, v_scales, axis: str,
+                  rows_sharded: bool, **kw):
+    """Run ``kernel`` under ``shard_map``: pool blocks (axis 0 of the
+    pages, scales and ``page_pos``) partition over ``axis``, heads over
+    'model' where ``_head_axis`` allows.  Shard s owns global block ids
+    [s*bps, (s+1)*bps) (the ShardedKVPool convention); each shard keeps
+    the table entries in its own segment, rebased to local ids, and masks
+    the rest (-1), so every page DMA hits a resident page.
+
+    rows_sharded: the rows (axis 0 of q, the tables and ``row_vecs``)
+    partition over ``axis`` too, which is exact when each shard's rows
+    only reference its own segment — the decode grid, collective-free.
+    Otherwise (a one-row prefill chunk lives on one shard) the rows are
+    replicated, every shard runs them against its own pages, and a psum
+    over ``axis`` keeps each row's output from the shard owning its
+    blocks."""
     from jax.sharding import PartitionSpec as P
-    return (P(axis, None, head, None), P(axis, None, head, None),
-            P(axis, None), P(axis))
+    bps = k_pages.shape[0] // mesh.shape[axis]
+    head = _head_axis(mesh, q.shape[2], k_pages.shape[2])
+    row = axis if rows_sharded else None
+    q_sp = P(row, None, head, None)
+    quantized = k_scales is not None
+    scales = (k_scales, v_scales) if quantized else ()
+
+    def local(qs, kp, vp, bt, pp, *rest):
+        ks, vs = rest[:2] if quantized else (None, None)
+        off = jax.lax.axis_index(axis) * bps
+        mine = (bt >= off) & (bt < off + bps)
+        o = kernel(qs, kp, vp, jnp.where(mine, bt - off, -1), pp,
+                   *rest[len(scales):], k_scales=ks, v_scales=vs, **kw)
+        if rows_sharded:
+            return o
+        owner = mine.any(axis=1)[:, None, None, None]
+        return jax.lax.psum(jnp.where(owner, o, 0), axis)
+
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(q_sp, P(axis, None, head, None), P(axis, None, head, None),
+                  P(row, None), P(axis, None),
+                  *[P(axis, None, head)] * len(scales),
+                  *[P(row)] * len(row_vecs)),
+        out_specs=q_sp, check_vma=False,
+    )(q, k_pages, v_pages, block_tables, page_pos, *scales, *row_vecs)
 
 
 def sharded_paged_attention(mesh, q, k_pages, v_pages, block_tables,
                             page_pos, q_pos, *, k_scales=None,
                             v_scales=None, window=None,
                             causal: bool = True, interpret: bool = False,
-                            axis: str = "data"):
-    """``paged_attention`` under ``shard_map``: rows (axis 0 of q /
-    block_tables / q_pos) and pool blocks (axis 0 of k_pages / v_pages /
-    page_pos) partition over the mesh's ``axis``; every shard runs the
-    single-device kernel against its local page segment with its tables
-    rebased to local ids.  Requires the ShardedKVPool invariant (a row's
-    table references only its own shard's segment) — collective-free.
-    When both head counts divide the 'model' axis, heads split over
-    'model' too (each model shard runs its own kv-head group); otherwise
-    they replicate over 'model'.  Quantized pools pass their
-    (P, BS, Hkv) scales, which shard exactly like the pages (blocks on
-    ``axis``, Hkv on the head axis)."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-    n = mesh.shape[axis]
-    bps = k_pages.shape[0] // n
-    head = _head_axis(mesh, q.shape[2], k_pages.shape[2])
-    q_sp, page_sp, bt_sp, vec_sp = _specs(mesh, axis, head)
-    quantized = k_scales is not None
-
-    if quantized:
-        sc_sp = P(axis, None, head)
-
-        def local(qs, kp, vp, ks, vs, bt, pp, qp):
-            return paged_attention(qs, kp, vp, _local_tables(bt, axis, bps),
-                                   pp, qp, k_scales=ks, v_scales=vs,
-                                   window=window, causal=causal,
-                                   interpret=interpret)
-
-        return shard_map(
-            local, mesh=mesh,
-            in_specs=(q_sp, page_sp, page_sp, sc_sp, sc_sp, bt_sp, bt_sp,
-                      vec_sp),
-            out_specs=q_sp, check_rep=False,
-        )(q, k_pages, v_pages, k_scales, v_scales, block_tables, page_pos,
-          q_pos)
-
-    def local(qs, kp, vp, bt, pp, qp):
-        return paged_attention(qs, kp, vp, _local_tables(bt, axis, bps),
-                               pp, qp, window=window, causal=causal,
-                               interpret=interpret)
-
-    return shard_map(
-        local, mesh=mesh,
-        in_specs=(q_sp, page_sp, page_sp, bt_sp, bt_sp, vec_sp),
-        out_specs=q_sp, check_rep=False,
-    )(q, k_pages, v_pages, block_tables, page_pos, q_pos)
+                            axis: str = "data", rows_sharded: bool = True):
+    """``paged_attention`` under ``shard_map`` (``_shard_mapped``): every
+    shard runs the single-device kernel against its local page segment.
+    With ``rows_sharded`` (the default) rows split over ``axis`` and the
+    call is collective-free; this needs the ShardedKVPool invariant (a
+    row's table references only its own shard's segment).  Quantized
+    pools pass their (P, BS, Hkv) scales, which shard exactly like the
+    pages (blocks on ``axis``, Hkv on the head axis)."""
+    return _shard_mapped(paged_attention, mesh, q, k_pages, v_pages,
+                         block_tables, page_pos, (q_pos,),
+                         k_scales=k_scales, v_scales=v_scales, axis=axis,
+                         rows_sharded=rows_sharded, window=window,
+                         causal=causal, interpret=interpret)
 
 
 def sharded_paged_prefill_attention(mesh, q, k_pages, v_pages,
@@ -392,42 +373,14 @@ def sharded_paged_prefill_attention(mesh, q, k_pages, v_pages,
                                     q_len, *, k_scales=None, v_scales=None,
                                     window=None, causal: bool = True,
                                     interpret: bool = False,
-                                    axis: str = "data"):
+                                    axis: str = "data",
+                                    rows_sharded: bool = True):
     """``paged_prefill_attention`` under ``shard_map`` — same partitioning
     and shard-locality contract (including the conditional 'model' head
-    split and quantized-scale handling) as ``sharded_paged_attention``."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-    n = mesh.shape[axis]
-    bps = k_pages.shape[0] // n
-    head = _head_axis(mesh, q.shape[2], k_pages.shape[2])
-    q_sp, page_sp, bt_sp, vec_sp = _specs(mesh, axis, head)
-    quantized = k_scales is not None
-
-    if quantized:
-        sc_sp = P(axis, None, head)
-
-        def local(qs, kp, vp, ks, vs, bt, pp, q0, ql):
-            return paged_prefill_attention(
-                qs, kp, vp, _local_tables(bt, axis, bps), pp, q0, ql,
-                k_scales=ks, v_scales=vs, window=window, causal=causal,
-                interpret=interpret)
-
-        return shard_map(
-            local, mesh=mesh,
-            in_specs=(q_sp, page_sp, page_sp, sc_sp, sc_sp, bt_sp, bt_sp,
-                      vec_sp, vec_sp),
-            out_specs=q_sp, check_rep=False,
-        )(q, k_pages, v_pages, k_scales, v_scales, block_tables, page_pos,
-          q_start, q_len)
-
-    def local(qs, kp, vp, bt, pp, q0, ql):
-        return paged_prefill_attention(
-            qs, kp, vp, _local_tables(bt, axis, bps), pp, q0, ql,
-            window=window, causal=causal, interpret=interpret)
-
-    return shard_map(
-        local, mesh=mesh,
-        in_specs=(q_sp, page_sp, page_sp, bt_sp, bt_sp, vec_sp, vec_sp),
-        out_specs=q_sp, check_rep=False,
-    )(q, k_pages, v_pages, block_tables, page_pos, q_start, q_len)
+    split, quantized-scale handling and ``rows_sharded``) as
+    ``sharded_paged_attention``."""
+    return _shard_mapped(paged_prefill_attention, mesh, q, k_pages, v_pages,
+                         block_tables, page_pos, (q_start, q_len),
+                         k_scales=k_scales, v_scales=v_scales, axis=axis,
+                         rows_sharded=rows_sharded, window=window,
+                         causal=causal, interpret=interpret)

@@ -1,13 +1,16 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture × input-shape ×
 mesh) cell against the production meshes, print memory/cost analysis, and
 record roofline terms.
 
-The two lines above MUST stay the first statements in this module — jax
+The lines above MUST stay the first statements in this module — jax
 locks the device count at first backend init, and only the dry-run may
-see 512 placeholder host devices.
+see 512 placeholder host devices.  They are host (CPU) devices, so the
+platform is pinned too: on a machine with an accelerator JAX would
+otherwise take it and fail to build the (16, 16) mesh.
 
 Usage:
     python -m repro.launch.dryrun --arch gemma-2b --shape train_4k

@@ -16,12 +16,20 @@ def make_production_mesh(*, multi_pod: bool = False):
     an outer data-parallel dimension crossing the DCN/ICI boundary."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 1, model: int = 1):
     """Small mesh over the locally available devices (tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto: the model code places
+    activations with ``with_sharding_constraint`` and leaves the rest to
+    GSPMD, which Explicit axes (``make_mesh``'s default) refuse."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_serve_mesh(data: int = 1, model: int = 1):

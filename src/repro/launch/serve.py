@@ -61,6 +61,7 @@ import numpy as np
 
 from repro.core import MuxSpec
 from repro.configs import get_config, model_kind
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import TransformerLM, VLM, EncDecLM
 from repro.serve import (ServeConfig, init_cache, prefill, decode_step,
                          MuxBatcher, Request, sampling)
@@ -627,6 +628,7 @@ def _parse_slo_mix(ap, spec: str):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
@@ -708,7 +710,7 @@ def main(argv=None):
                     help="paged continuous serving: route decode/chunk "
                          "attention through the Pallas paged kernels "
                          "(with --mesh: the shard_map'd shard-local "
-                         "decode kernel; interpret mode off-TPU)")
+                         "kernels; interpret mode on the CPU)")
     ap.add_argument("--arrival-every", type=int, default=2,
                     help="continuous: one request arrives every K steps")
     ap.add_argument("--shards", type=int, default=None, metavar="N",

@@ -32,9 +32,11 @@ from repro.train import make_train_step, jit_step, causal_lm_loss
 from repro.train.mux_stages import retrieval_stage, mlm_stage
 from repro.checkpoint import AsyncCheckpointManager
 from repro.runtime import Supervisor, StragglerDetector
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default=None,
                     help="mux-bert-{small,base,large} | mux-electra-base")

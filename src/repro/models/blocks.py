@@ -424,16 +424,18 @@ def apply_attention(p, cfg, blk, x, ctx, cache):
                 scale_kw = ({"k_scales": cache["ksc"],
                              "v_scales": cache["vsc"]}
                             if "ksc" in cache else {})
-                if (mesh is not None and mesh.shape.get("data", 1) > 1
-                        and cache["bt"].shape[0] % mesh.shape["data"] == 0):
-                    # shard_map: each data shard runs the kernel over its
-                    # resident pages only (block tables are shard-local
-                    # by the ShardedKVPool invariant) — no cross-device
-                    # page gathers on the decode path
+                if mesh is not None:
+                    # shard_map (GSPMD cannot partition a Mosaic kernel):
+                    # each data shard runs the kernel over its resident
+                    # pages only (block tables are shard-local by the
+                    # ShardedKVPool invariant) — no cross-device page
+                    # gathers on the decode path
                     o = kops.sharded_paged_attention(
                         mesh, q, cache["kp"], cache["vp"], cache["bt"],
                         cache["ppos"], posm[:, 0], window=window,
-                        causal=cfg.causal, **scale_kw)
+                        causal=cfg.causal,
+                        rows_sharded=b % mesh.shape["data"] == 0,
+                        **scale_kw)
                 else:
                     o = kops.paged_attention(
                         q, cache["kp"], cache["vp"], cache["bt"],
@@ -480,17 +482,16 @@ def apply_attention(p, cfg, blk, x, ctx, cache):
             mesh = ctx.get("mesh")
             scale_kw = ({"k_scales": cache["ksc"], "v_scales": cache["vsc"]}
                         if "ksc" in cache else {})
-            # shard_map only for FULL-GRID chunk batches: a rows= subset
-            # has no guaranteed row->shard alignment (shard_map would
-            # rebase a row's block ids against the wrong shard's offset
-            # and silently mask its context), so subsets always take the
-            # GSPMD-partitioned kernel below
-            if (mesh is not None and mesh.shape.get("data", 1) > 1
-                    and rows is None
-                    and bt.shape[0] % mesh.shape["data"] == 0):
+            if mesh is not None:
+                # rows split over 'data' only for FULL-GRID chunk
+                # batches: a rows= subset has no guaranteed row->shard
+                # alignment, so its rows replicate and the owning
+                # shard's output is kept (psum)
                 o = kops.sharded_paged_prefill_attention(
                     mesh, q, cache["kp"], cache["vp"], bt, cache["ppos"],
                     q_start, q_len, window=window, causal=cfg.causal,
+                    rows_sharded=(rows is None
+                                  and b % mesh.shape["data"] == 0),
                     **scale_kw)
             else:
                 o = kops.paged_prefill_attention(

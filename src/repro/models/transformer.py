@@ -106,12 +106,17 @@ class TransformerLM:
         Returns dict(logits | hidden, aux, cache).
         """
         d = cfg.d_model
+        # The mux entry/exit kernels take whole (unsharded) weights, and
+        # GSPMD cannot partition a Mosaic kernel: on a multi-device mesh
+        # they run as XLA ops (the paged attention kernels shard_map).
+        mesh = (extra_ctx or {}).get("mesh")
+        mux_kernels = use_kernels and (mesh is None or mesh.size == 1)
         # Fused decode entry: embed-gather + embedding-scale + Gaussian
         # mux-combine as ONE Pallas launch (kernels/mux_embed.py) — the
         # (N*B, L, D) embeddings never materialize.  Gated to the
         # gaussian/rsa mux config (contextual mux runs transformer
         # layers; the prefix demux splices extra positions in combine).
-        fuse_entry = (use_kernels and embeds is None and mux.enabled
+        fuse_entry = (mux_kernels and embeds is None and mux.enabled
                       and mux.mux_kind == "gaussian"
                       and mux.demux_kind != "prefix"
                       and "mux_engine" in params)
@@ -212,7 +217,7 @@ class TransformerLM:
 
         # Fused decode exit: backbone final norm + RSA demux + demux-LN
         # as ONE Pallas launch (kernels/demux_rsa.py epilogue fusion).
-        fuse_exit = (use_kernels and demux and mux.enabled
+        fuse_exit = (mux_kernels and demux and mux.enabled
                      and mux.demux_kind == "rsa" and "mux_engine" in params)
         if fuse_exit:
             x = MuxEngine.separate_fused(
@@ -226,7 +231,7 @@ class TransformerLM:
             # --- demultiplex ---------------------------------------------
             if demux:
                 x = MuxEngine.separate(params.get("mux_engine", {}), mux, x,
-                                       use_kernel=use_kernels)
+                                       use_kernel=mux_kernels)
 
         out = {"aux": aux_total}
         if decode:

@@ -16,7 +16,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.optim.compression import compress_tree_psum, init_error_state
 
@@ -48,11 +47,11 @@ def make_compressed_dp_step(loss_fn: Callable, optimizer, *, mesh: Mesh,
                 {**metrics, **om, "loss": loss})
 
     rep = P()
-    f = shard_map(
+    f = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(rep, P(axis_name), rep),
         out_specs=(rep, rep),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(f)
 
 

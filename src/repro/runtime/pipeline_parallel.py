@@ -21,7 +21,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stacked_params, x, *, mesh: Mesh,
@@ -63,8 +62,8 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x, *, mesh: Mesh,
             jnp.where(stage == n_stages - 1, collected, 0.0), axis_name)
 
     pspec = jax.tree.map(lambda _: P(axis_name), stacked_params)
-    f = shard_map(body, mesh=mesh, in_specs=(pspec, P()), out_specs=P(),
-                  check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(pspec, P()),
+                      out_specs=P(), check_vma=False)
     return f(stacked_params, x)
 
 

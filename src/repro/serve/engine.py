@@ -163,11 +163,12 @@ def set_block_tables(cache, block_tables):
     """Install a host (B, max_blocks_per_seq) block-table array into every
     paged layer of a cache pytree (period-stacked layers broadcast over
     the period axis).  Call after KVPool alloc/append/free changed any
-    row's table."""
-    bt = jnp.asarray(block_tables, jnp.int32)
-
+    row's table.  Every layer gets a buffer of its own: the jitted steps
+    donate the cache, and a buffer shared by two leaves cannot be donated
+    twice."""
     def upd(c):
         if isinstance(c, dict) and "bt" in c:
+            bt = jnp.array(block_tables, jnp.int32)        # always a copy
             return {**c, "bt": jnp.broadcast_to(bt, c["bt"].shape)}
         return c
 

@@ -29,9 +29,8 @@ up front instead of once per prompt length:
 
 A joining row advances one chunk per engine step while live rows keep
 decoding — admission never stalls the grid behind a long prompt.  Cache
-buffers are donated to the jitted steps on accelerator backends (XLA
-updates the pool in place; CPU does not implement donation, so it is
-skipped there to avoid per-step warnings).
+buffers are donated to the jitted steps (XLA updates the pool in place),
+so nothing may hold a cache pytree across a step.
 
 Pool pressure flows runtime -> scheduler: an admission that cannot get
 blocks is rolled back (``cancel_admit``) and retried after rows drain; a
@@ -224,9 +223,10 @@ class ServeRuntime:
                       "prefill_mode": ("chunked" if chunk is not None
                                        else "blocking")}
         # donation: the cache pytree (arg 1) is consumed and returned by
-        # every step — in-place on TPU/GPU, skipped on CPU (unsupported)
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        jit_kw = {}
+        # every step, so XLA updates the pool in place.  Every backend
+        # honours it, so a stale read of a pre-step cache fails on the
+        # CPU exactly as it would on the chip.
+        jit_kw = {"donate_argnums": (1,)}
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
             # tokens come back replicated (the one host gather per step);
@@ -234,10 +234,8 @@ class ServeRuntime:
             # signature of the next step is identical
             jit_kw["out_shardings"] = (NamedSharding(mesh, P()),
                                        self._cache_sh)
-        self._decode_jit = jax.jit(self._decode_impl,
-                                   donate_argnums=donate, **jit_kw)
-        self._chunk_jit = jax.jit(self._chunk_impl,
-                                  donate_argnums=donate, **jit_kw)
+        self._decode_jit = jax.jit(self._decode_impl, **jit_kw)
+        self._chunk_jit = jax.jit(self._chunk_impl, **jit_kw)
 
     # -- jitted step bodies (traced once per shape signature) --------------
     def _traced(self, key: str):

@@ -338,6 +338,41 @@ def test_sharded_paged_prefill_attention_matches_ref():
                                atol=3e-5, rtol=1e-4)
 
 
+@pytest.mark.parametrize("rows", [[2], [1, 2, 3]])
+def test_sharded_paged_kernels_replicated_rows_match_ref(rows):
+    """Row subsets that do not split evenly over 'data' (the runtime's
+    one-row prefill chunk): the rows replicate, every shard runs them
+    against its own pages, and the psum keeps each row's owning shard.
+    On four devices the mesh is (data=2, model=2), so heads split over
+    'model' too; on one device the same path runs on a 1x1 mesh."""
+    from repro.kernels import ops, ref
+    data, model = (2, 2) if jax.device_count() >= 4 else (1, 1)
+    mesh = make_serve_mesh(data, model)
+    kp, vp, bt, ppos = _sharded_pool([20, 9, 13, 5], n_shards=data,
+                                     bps=16 // data, block_size=8,
+                                     max_blocks=4, hkv=2, dh=16,
+                                     key=jax.random.fold_in(KEY, 4))
+    sub = jnp.asarray(rows)
+    bt = bt[sub]
+    q = jax.random.normal(jax.random.fold_in(KEY, 5), (len(rows), 4, 8, 16))
+    q_start = jnp.asarray([16, 5, 9, 1], jnp.int32)[sub]
+    q_len = jnp.asarray([4, 4, 4, 3], jnp.int32)[sub]
+    got = ops.sharded_paged_prefill_attention(
+        mesh, q, kp, vp, bt, ppos, q_start, q_len, rows_sharded=False)
+    want = ref.paged_prefill_attention_ref(q, kp, vp, bt, ppos, q_start,
+                                           q_len)
+    for i, n in enumerate(np.asarray(q_len)):     # valid queries only
+        np.testing.assert_allclose(np.asarray(got)[i, :n],
+                                   np.asarray(want)[i, :n],
+                                   atol=3e-5, rtol=1e-4)
+    q_pos = q_start + q_len - 1
+    got = ops.sharded_paged_attention(mesh, q[:, :1], kp, vp, bt, ppos,
+                                      q_pos, rows_sharded=False)
+    want = ref.paged_attention_ref(q[:, :1], kp, vp, bt, ppos, q_pos)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=3e-5, rtol=1e-4)
+
+
 # ------------------------------------- specs + validation (always run)
 
 class FakeMesh:

@@ -300,3 +300,18 @@ def test_continuous_ring_vs_paged_prefill_cost():
     assert s_paged["prefill_tokens"] < s_ring["prefill_tokens"]
     # paged: blocks all returned to the pool at drain
     assert s_paged["pool"].n_used_blocks == 0
+
+
+def test_set_block_tables_gives_each_layer_its_own_buffer():
+    """The jitted serve steps donate the cache pytree, and one buffer
+    behind two leaves cannot be donated twice: every unstacked paged
+    layer must get a table buffer of its own."""
+    cache = {"periods": (),
+             "tail": tuple({"bt": jnp.full((2, 3), -1, jnp.int32)}
+                           for _ in range(2))}
+    cache = set_block_tables(cache, np.arange(6, dtype=np.int32)
+                             .reshape(2, 3))
+    out = jax.jit(lambda c: c, donate_argnums=0)(cache)
+    for layer in out["tail"]:
+        np.testing.assert_array_equal(np.asarray(layer["bt"]),
+                                      np.arange(6).reshape(2, 3))
