@@ -69,7 +69,7 @@ from repro.serve.engine import (ServeConfig, init_cache, make_pool, prefill,
                                 reset_blocks, copy_cache_pages)
 from repro.serve.kvpool import PoolExhausted
 from repro.serve.scheduler import ContinuousScheduler
-from repro.serve.telemetry import NULL_TELEMETRY
+from repro.serve.telemetry import NULL_SPAN, NULL_TELEMETRY
 
 MIN_BUCKET = 4
 
@@ -409,9 +409,7 @@ class ServeRuntime:
         # the dead rows' tables drop to all -1 on device: they stop
         # addressing the dead segment's pages (shapes unchanged — the
         # jitted steps never re-trace across a kill)
-        self.cache = set_block_tables(
-            self.cache, self.pool.table_array(range(self.nrows)))
-        self._commit_cache()
+        self._install_tables()
         if self.tele.enabled:
             self.tele.inc("shards_lost", lane=self.lane, shard=shard)
             self.tele.inc("requests_replayed", len(replayed),
@@ -482,17 +480,13 @@ class ServeRuntime:
         nbytes = (len(src_blocks) * self.sc.block_size
                   * self.sc.kv_bytes_per_token())
         with self.tele.span("handoff", lane=self.lane, dst_lane=dst.lane,
-                            metric="handoff_s", row=j, dst_row=dst_row,
-                            tokens=plan.tokens, blocks=len(src_blocks),
-                            bytes=nbytes):
+                            metric="handoff_s", step=self.engine_steps,
+                            row=j, dst_row=dst_row, tokens=plan.tokens,
+                            blocks=len(src_blocks), bytes=nbytes):
             dst.cache = copy_cache_pages(self.cache, dst.cache,
                                          src_blocks, dst_blocks)
-            self.cache = set_block_tables(
-                self.cache, self.pool.table_array(range(self.nrows)))
-            self._commit_cache()
-            dst.cache = set_block_tables(
-                dst.cache, dst.pool.table_array(range(dst.nrows)))
-            dst._commit_cache()
+            self._install_tables()
+            dst._install_tables()
             slots = self.sched.retire_handoff(plan)
             dst.sched.admit_handoff(plan, slots)
             dst.row_len[dst_row] = self.row_len.pop(j)
@@ -543,16 +537,20 @@ class ServeRuntime:
         never admits from its own queue (streams preempted there are
         re-routed by the orchestrator, since re-prefill is prefill-lane
         work)."""
-        with self.tele.span("engine_step", lane=self.lane,
-                            metric="step_latency_s"):
+        with (self.tele.span("engine_step", lane=self.lane,
+                             metric="step_latency_s", step=self.engine_steps)
+              if self.tele.enabled else NULL_SPAN):
             if self.role != "decode":
                 self._exec_admissions()
-                for plan in self.sched.plan_chunks(self.chunk):
+                with self._inputs_span("plan"):
+                    chunks = self.sched.plan_chunks(self.chunk)
+                for plan in chunks:
                     self._exec_chunk(plan)
                 self._exec_frees()         # e.g. max_new=1 done at prefill
             if self.role != "prefill":
-                dp = self.sched.plan_decode()
-                rows = [j for j in dp.rows if j in self.row_len]
+                with self._inputs_span("plan"):
+                    dp = self.sched.plan_decode()
+                    rows = [j for j in dp.rows if j in self.row_len]
                 if rows:
                     self._exec_decode(rows)
                     self._exec_frees()
@@ -573,13 +571,60 @@ class ServeRuntime:
                 self.tele.gauge("pool_quota_blocks", st["quota"],
                                 lane=self.lane, shard=s)
 
+    # -- spans around the host work of a step -----------------------------
+    # Every span the runtime records carries ``step``, the index of the
+    # engine step it belongs to.  A span with arguments is only built
+    # when telemetry is on: off, each hook costs one ``enabled`` check.
+    def _edit_span(self, kind: str, blocks: int):
+        """``cache_edit``: an eager edit of the cache pytree outside the
+        jitted steps (``reset`` / ``tables`` / ``commit``) naming
+        ``blocks`` pool blocks or block-table entries."""
+        if not self.tele.enabled:
+            return NULL_SPAN
+        return self.tele.span("cache_edit", lane=self.lane,
+                              step=self.engine_steps, kind=kind,
+                              blocks=blocks)
+
+    def _inputs_span(self, phase: str):
+        """``step_inputs``: host work that plans a step (``plan``) or
+        builds the inputs of a decode call (``decode``) or of a prefill
+        chunk (``chunk``)."""
+        if not self.tele.enabled:
+            return NULL_SPAN
+        return self.tele.span("step_inputs", lane=self.lane,
+                              step=self.engine_steps, phase=phase)
+
+    def _row_span(self, name: str, j: int, metric=None, **args):
+        """``admit`` / ``prefill_chunk`` over row ``j``, with the request
+        ids of its row group (only called with telemetry on)."""
+        uids = [s.request.uid for s in self.sched.slots[j]
+                if s.request is not None]
+        return self.tele.span(name, lane=self.lane,
+                              shard=self.sched.shard_of(j), metric=metric,
+                              step=self.engine_steps, row=j, uids=uids,
+                              **args)
+
+    def _reset_blocks(self, blocks):
+        with self._edit_span("reset", len(blocks)):
+            self.cache = reset_blocks(self.cache, blocks)
+
+    def _install_tables(self):
+        """Install every row's block table from the pool and re-commit
+        the cache."""
+        with self._edit_span("tables", self.nrows
+                             * self.pool.max_blocks_per_seq):
+            self.cache = set_block_tables(
+                self.cache, self.pool.table_array(range(self.nrows)))
+        self._commit_cache()
+
     def _commit_cache(self):
         """Re-assert the pinned NamedShardings after a host-side cache
         edit (set_block_tables / reset_blocks build fresh arrays whose
         sharding would otherwise drift and force a silent re-trace of
         the jitted steps on their next call)."""
         if self._cache_sh is not None:
-            self.cache = jax.device_put(self.cache, self._cache_sh)
+            with self._edit_span("commit", self.pool.num_blocks):
+                self.cache = jax.device_put(self.cache, self._cache_sh)
 
     def _shard_used_blocks(self, row: int) -> int:
         """Used blocks on ``row``'s shard (the whole pool when unsharded)
@@ -596,13 +641,13 @@ class ServeRuntime:
         blocks instead of head-of-line blocking the queue."""
         failed: set = set()
         admitted = False
-        plans = self.sched.plan_admissions(self.pad_id)
+        with self._inputs_span("plan"):
+            plans = self.sched.plan_admissions(self.pad_id)
         while plans:
             retry = False
             for plan in plans:
-                with self.tele.span("admit", lane=self.lane,
-                                    shard=plan.shard, row=plan.row,
-                                    tokens=plan.total):
+                with (self._row_span("admit", plan.row, tokens=plan.total)
+                      if self.tele.enabled else NULL_SPAN):
                     ok = self._exec_admit(plan)
                 if ok:
                     admitted = True
@@ -614,43 +659,44 @@ class ServeRuntime:
                 break
             # every iteration adds at least one newly failed shard, so
             # this terminates after <= n_shards rounds
-            plans = self.sched.plan_admissions(self.pad_id,
-                                               skip_shards=failed)
+            with self._inputs_span("plan"):
+                plans = self.sched.plan_admissions(self.pad_id,
+                                                   skip_shards=failed)
         if admitted:
             # one combined table install + sharding re-commit for ALL of
             # this step's admissions (per-plan block resets already
             # happened; rebuilding the (nrows, MB) table array and
             # re-committing the cache pytree per plan would be redundant)
-            self.cache = set_block_tables(
-                self.cache, self.pool.table_array(range(self.nrows)))
-            self._commit_cache()
+            self._install_tables()
 
     def _exec_admit(self, plan) -> bool:
-        try:
-            blocks = self.pool.allocate(plan.row, plan.total)
-        except PoolExhausted:
-            # backpressure: roll the group back and retry once blocks
-            # free up; later groups still get their shot.  The verdict
-            # is shard-local: only the plan's own shard can ever free
-            # the blocks this group is waiting for.
-            self.sched.cancel_admit(plan)
-            if self.tele.enabled:
-                self.tele.inc("admit_rollbacks", lane=self.lane,
-                              shard=plan.shard)
-                self.tele.instant("cancel", lane=self.lane,
-                                  shard=plan.shard, row=plan.row,
-                                  tokens=plan.total)
-            if self._shard_used_blocks(plan.row) == 0:
-                raise PoolExhausted(
-                    f"request group of {plan.total} tokens cannot fit "
-                    f"an empty pool shard (num_blocks="
-                    f"{self.pool.num_blocks}, block_size="
-                    f"{self.pool.block_size}, shards {self.sc.n_shards}, "
-                    f"per-seq cap {self.pool.max_blocks_per_seq})")
-            return False
-        self.row_len[plan.row] = plan.total
-        self.row_tokens[plan.row] = np.asarray(plan.tokens, np.int32)
-        self.cache = reset_blocks(self.cache, blocks)
+        with self._inputs_span("plan"):
+            try:
+                blocks = self.pool.allocate(plan.row, plan.total)
+            except PoolExhausted:
+                # backpressure: roll the group back and retry once blocks
+                # free up; later groups still get their shot.  The verdict
+                # is shard-local: only the plan's own shard can ever free
+                # the blocks this group is waiting for.
+                self.sched.cancel_admit(plan)
+                if self.tele.enabled:
+                    self.tele.inc("admit_rollbacks", lane=self.lane,
+                                  shard=plan.shard)
+                    self.tele.instant("cancel", lane=self.lane,
+                                      shard=plan.shard, row=plan.row,
+                                      tokens=plan.total)
+                if self._shard_used_blocks(plan.row) == 0:
+                    raise PoolExhausted(
+                        f"request group of {plan.total} tokens cannot fit "
+                        f"an empty pool shard (num_blocks="
+                        f"{self.pool.num_blocks}, block_size="
+                        f"{self.pool.block_size}, shards "
+                        f"{self.sc.n_shards}, per-seq cap "
+                        f"{self.pool.max_blocks_per_seq})")
+                return False
+            self.row_len[plan.row] = plan.total
+            self.row_tokens[plan.row] = np.asarray(plan.tokens, np.int32)
+        self._reset_blocks(blocks)
         return True
 
     def _bucket(self, n: int) -> int:
@@ -660,19 +706,26 @@ class ServeRuntime:
         return self.buckets[-1]
 
     def _exec_chunk(self, plan):
-        j = plan.row
-        with self.tele.span("prefill_chunk", lane=self.lane,
-                            shard=self.sched.shard_of(j),
-                            metric="prefill_chunk_s", row=j,
-                            start=plan.start, length=plan.length,
-                            last=plan.last):
+        with (self._row_span("prefill_chunk", plan.row,
+                             metric="prefill_chunk_s", start=plan.start,
+                             length=plan.length, last=plan.last)
+              if self.tele.enabled else NULL_SPAN):
             self._exec_chunk_inner(plan)
+
+    def _chunk_buffer(self, plan):
+        """The chunk's tokens, padded to its shape bucket."""
+        buf = np.full((self.n_mux, self._bucket(plan.length)), self.pad_id,
+                      np.int32)
+        buf[:, :plan.length] = self.row_tokens[plan.row][
+            :, plan.start:plan.start + plan.length]
+        return buf
 
     def _exec_chunk_inner(self, plan):
         j = plan.row
-        toks = self.row_tokens[j][:, plan.start:plan.start + plan.length]
-        arr, steps = self._sampling_row(j)
-        if self.chunk is None:
+        with self._inputs_span("chunk"):
+            arr, steps = self._sampling_row(j)
+            buf = self._chunk_buffer(plan) if self.chunk is not None else None
+        if buf is None:
             # blocking prefill: whole prompt, eager, fresh-KV attention
             compute = plan.length
             trash = (self._trash[jnp.asarray([j])]
@@ -685,9 +738,7 @@ class ServeRuntime:
             out = sampling.sample(logits, arr["temperature"], arr["top_k"],
                                   arr["top_p"], arr["seed"], steps)
         else:
-            compute = self._bucket(plan.length)
-            buf = np.full((self.n_mux, compute), self.pad_id, np.int32)
-            buf[:, :plan.length] = toks
+            compute = buf.shape[1]
             out, self.cache = self._chunk_jit(
                 self.params, self.cache, buf, np.int32(j),
                 np.int32(plan.start), np.int32(plan.length),
@@ -728,6 +779,45 @@ class ServeRuntime:
         return len(self.row_len)
 
     def _exec_decode(self, rows):
+        with self._inputs_span("decode"):
+            pos_vec, fresh, preempt = self._append_slots(rows)
+        if fresh:
+            self._reset_blocks(fresh)
+        if fresh or preempt:
+            self._install_tables()
+        rows = [j for j in rows if j not in preempt]
+        if not rows:
+            return
+        with self._inputs_span("decode"):
+            self._clear_dead_slots()
+            toks_in = self.next_tok.reshape(-1)[:, None]
+            temps, top_k, top_p, seeds, steps = self._sampling_grid()
+        with (self.tele.span("decode", lane=self.lane,
+                             metric="decode_step_s", step=self.engine_steps,
+                             rows=len(rows))
+              if self.tele.enabled else NULL_SPAN):
+            out, self.cache = self._decode_jit(
+                self.params, self.cache, toks_in, pos_vec, temps, top_k,
+                top_p, seeds, steps)
+            # the one existing device->host gather per decode step; the
+            # span closes after it, so decode_step_s covers dispatch +
+            # this read-back (no NEW sync), and the timestamp below is
+            # the step's uniform token-arrival stamp for every stream
+            grid = np.asarray(out).reshape(self.n_mux, self.nrows)
+        now = time.time()
+        with self._inputs_span("decode"):
+            for j in rows:
+                self.sched.record_row_tokens(j, grid[:, j], now=now)
+                self.row_len[j] += 1
+            self.next_tok = grid.copy()
+            self.stats["decode_steps"] += 1
+            self.stats["slot_util"].append(self.sched.utilization())
+            self.stats["cache_util"].append(self.pool.utilization())
+
+    def _append_slots(self, rows):
+        """Reserve each decoding row's next slot; rows whose shard is
+        full are preempted.  Returns (positions, fresh blocks, preempted
+        rows)."""
         pos_vec = np.full((self.nrows,), -1, np.int32)
         fresh, preempt = [], []
         for j in rows:
@@ -758,44 +848,16 @@ class ServeRuntime:
                 self.tele.inc("preempts", lane=self.lane, shard=shard)
                 self.tele.instant("preempt", lane=self.lane, shard=shard,
                                   row=j)
-        if fresh:
-            self.cache = reset_blocks(self.cache, fresh)
-        if fresh or preempt:
-            self.cache = set_block_tables(
-                self.cache, self.pool.table_array(range(self.nrows)))
-            self._commit_cache()
-        rows = [j for j in rows if j not in preempt]
-        if not rows:
-            return
-        self._clear_dead_slots()
-        toks_in = self.next_tok.reshape(-1)[:, None]
-        temps, top_k, top_p, seeds, steps = self._sampling_grid()
-        with self.tele.span("decode", lane=self.lane,
-                            metric="decode_step_s", rows=len(rows)):
-            out, self.cache = self._decode_jit(
-                self.params, self.cache, toks_in, pos_vec, temps, top_k,
-                top_p, seeds, steps)
-            # the one existing device->host gather per decode step; the
-            # span closes after it, so decode_step_s covers dispatch +
-            # this read-back (no NEW sync), and the timestamp below is
-            # the step's uniform token-arrival stamp for every stream
-            grid = np.asarray(out).reshape(self.n_mux, self.nrows)
-        now = time.time()
-        for j in rows:
-            self.sched.record_row_tokens(j, grid[:, j], now=now)
-            self.row_len[j] += 1
-        self.next_tok = grid.copy()
-        self.stats["decode_steps"] += 1
-        self.stats["slot_util"].append(self.sched.utilization())
-        self.stats["cache_util"].append(self.pool.utilization())
+        return pos_vec, fresh, preempt
 
     def _exec_frees(self):
-        for plan in self.sched.plan_frees():
-            if plan.row in self.row_len:
-                self.pool.free(plan.row)
-                del self.row_len[plan.row]
-                del self.row_tokens[plan.row]
-                if self.tele.enabled:
-                    self.tele.instant("free", lane=self.lane,
-                                      shard=self.sched.shard_of(plan.row),
-                                      row=plan.row)
+        with self._inputs_span("plan"):
+            for plan in self.sched.plan_frees():
+                if plan.row in self.row_len:
+                    self.pool.free(plan.row)
+                    del self.row_len[plan.row]
+                    del self.row_tokens[plan.row]
+                    if self.tele.enabled:
+                        self.tele.instant(
+                            "free", lane=self.lane,
+                            shard=self.sched.shard_of(plan.row), row=plan.row)

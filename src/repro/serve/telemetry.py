@@ -271,7 +271,13 @@ class StepTracer:
     since the tracer's construction (``perf_counter`` based — monotonic,
     sub-µs resolution).  In the exported trace the ``pid`` is the
     serving lane and the ``tid`` the data shard, so Perfetto renders one
-    process track per lane with per-shard rows.
+    process track per lane with per-shard rows.  The export's
+    ``otherData.clock_anchor`` holds the ``perf_counter_ns`` and
+    ``time_ns`` readings taken together at construction: an event's
+    wall-clock time is ``time_ns + ts * 1000`` ns.  A ``jax.profiler``
+    trace stamps its events in ns after its ``profile_start_time``
+    (wall clock), so the anchor lays the exported spans over a device
+    profile.
     """
 
     def __init__(self, capacity: int = 65536):
@@ -280,7 +286,9 @@ class StepTracer:
         self.capacity = capacity
         self.events: collections.deque = collections.deque(maxlen=capacity)
         self.dropped = 0
-        self._t0 = time.perf_counter()
+        t0_ns = time.perf_counter_ns()
+        self._anchor = {"perf_counter_ns": t0_ns, "time_ns": time.time_ns()}
+        self._t0 = t0_ns * 1e-9
         self._pid_names: dict = {}
 
     def now_us(self) -> float:
@@ -320,7 +328,8 @@ class StepTracer:
                 ev["args"] = args
             events.append(ev)
         return {"traceEvents": events, "displayTimeUnit": "ms",
-                "otherData": {"dropped_events": self.dropped}}
+                "otherData": {"dropped_events": self.dropped,
+                              "clock_anchor": dict(self._anchor)}}
 
     def export(self, path):
         with open(path, "w") as f:
@@ -342,7 +351,7 @@ class _NullSpan:
         return False
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NullSpan()
 
 
 class _Span:
@@ -423,13 +432,20 @@ class Telemetry:
     def span(self, name: str, *, lane: int = 0, shard: int = 0,
              metric: str | None = None, **args):
         if not self.enabled:
-            return _NULL_SPAN
+            return NULL_SPAN
         return _Span(self, name, lane, shard, metric, args)
 
     def instant(self, name: str, *, lane: int = 0, shard: int = 0, **args):
         if self.enabled:
             self.tracer.instant(name, pid=lane, tid=shard,
                                 args=args or None)
+            if self.annotate:
+                # a zero-length annotation puts the instant on the
+                # profiler's host plane beside the spans
+                ann = _trace_annotation(name)
+                if ann is not None:
+                    with ann:
+                        pass
 
     def inc(self, name: str, n: int = 1, **labels):
         if self.enabled:

@@ -16,9 +16,10 @@ prompt length, generation budget, pool pressure) are served through:
                       fixed-width run at that lane's N, with compile
                       counts of 1 decode + one per bucket per width;
   * telemetry       — paged-chunked with a live ``serve.telemetry``
-                      session: token- and compile-count-identical to
-                      the uninstrumented run (observability must add
-                      no host syncs and no jit inputs);
+                      session, plain and with profiler annotations:
+                      token- and compile-count-identical to the
+                      uninstrumented run (observability must add no
+                      host syncs and no jit inputs);
   * quantized KV    — paged-chunked with int8 pages + fused-dequant
                       kernels (``ServeConfig(kv_dtype='int8')``): same
                       churn schedules as the bf16-page arm, greedy
@@ -209,6 +210,11 @@ def _fuzz_telemetry_once(cfg, params, seed):
         return tokens, dict(stats["trace_counts"]), stats
 
     base_tokens, base_traces, _ = arm()
+    # annotated: every span and instant also enters a jax.profiler
+    # TraceAnnotation, which must not perturb serving either
+    ann_tokens, ann_traces, _ = arm(Telemetry(annotate=True))
+    assert ann_tokens == base_tokens, "annotation changed the token streams"
+    assert ann_traces == base_traces, "annotation changed the compile counts"
     tele = Telemetry(snapshot_every=2)
     tokens, traces, stats = arm(tele)
     assert tokens == base_tokens, "telemetry changed the token streams"

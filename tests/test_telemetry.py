@@ -84,7 +84,12 @@ def test_histogram_merge_property(xs, ys):
         b.observe(y); both.observe(y)
     a.merge(b)
     assert a.count == both.count == len(xs) + len(ys)
-    assert a.snapshot() == both.snapshot()
+    got, want = a.snapshot(), both.snapshot()
+    # merging adds the two partial sums, so the float total may round
+    # differently from summing the samples in order
+    for k in ("sum", "mean"):
+        assert got.pop(k) == pytest.approx(want.pop(k))
+    assert got == want
 
 
 # ------------------------------------------------- registry + prometheus
@@ -153,6 +158,8 @@ def test_tracer_chrome_schema_roundtrip(tmp_path):
     i = next(e for e in evs if e["ph"] == "i")
     assert i["s"] == "t" and i["args"] == {"row": 3}
     assert doc["otherData"]["dropped_events"] == 0
+    assert set(doc["otherData"]["clock_anchor"]) == {"perf_counter_ns",
+                                                     "time_ns"}
 
 
 def test_tracer_ring_buffer_drops_oldest():
@@ -213,3 +220,149 @@ def test_snapshot_interval_and_exports(tmp_path):
     tpath = tmp_path / "trace.json"
     tele.write_trace(tpath)
     assert "traceEvents" in json.loads(tpath.read_text())
+
+
+def test_tracer_clock_anchor_maps_spans_to_wall_time():
+    import time
+    before = time.time_ns()
+    tr = StepTracer()
+    after = time.time_ns()
+    anchor = tr.chrome_trace()["otherData"]["clock_anchor"]
+    assert before <= anchor["time_ns"] <= after
+    # the tracer's zero is the anchor's perf_counter reading
+    assert tr._t0 == pytest.approx(anchor["perf_counter_ns"] * 1e-9)
+    t_ns = time.perf_counter_ns()
+    wall = time.time_ns()
+    ts_us = (t_ns - anchor["perf_counter_ns"]) / 1e3
+    assert abs(anchor["time_ns"] + ts_us * 1e3 - wall) < 5e6     # 5 ms
+
+
+def test_annotated_instant_reaches_the_profiler(monkeypatch):
+    from repro.serve import telemetry as tm
+    seen = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(tm, "_trace_annotation", Ann)
+    Telemetry(annotate=True).instant("preempt", lane=0, row=1)
+    assert seen == [("enter", "preempt"), ("exit", "preempt")]
+    seen.clear()
+    Telemetry().instant("preempt", lane=0)
+    NULL_TELEMETRY.instant("preempt", lane=0)
+    assert seen == []
+
+
+# ------------------------------------------------- runtime spans
+
+ROWS, CAPACITY, BLOCK, CHUNK = 2, 24, 4, 4
+
+
+@pytest.fixture(scope="module")
+def mux_model():
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config
+    from repro.core import MuxSpec
+    from repro.models import TransformerLM
+    from repro.serve import ServeConfig
+    cfg = get_config("qwen2-1.5b", reduced=True)
+    mux = MuxSpec(n=2)
+    params = TransformerLM.init(jax.random.PRNGKey(0), cfg, mux)
+    sc = ServeConfig(cfg=cfg, kind="lm", mux=mux, capacity=CAPACITY,
+                     dtype=jnp.float32, cache_layout="paged",
+                     block_size=BLOCK)
+    return cfg, params, sc
+
+
+def _serve(mux_model, telemetry=None, uid0=0):
+    """Serve five requests (two row groups, then one more) to the end
+    through a fresh runtime; returns it."""
+    from repro.serve import Request
+    from repro.serve.runtime import ServeRuntime
+    cfg, params, sc = mux_model
+    rt = ServeRuntime(params, sc, ROWS, chunk=CHUNK, telemetry=telemetry)
+    rng = np.random.default_rng(0)
+    for k, (n, new) in enumerate([(6, 7), (9, 5), (3, 6), (5, 8), (7, 3)]):
+        rt.submit(Request(uid=uid0 + k, max_new=new, prompt=list(
+            rng.integers(1, cfg.vocab_size, size=n, dtype=np.int32))))
+    while rt.has_work():
+        rt.step()
+    return rt
+
+
+def _spans(tele):
+    return [(n, ts, dur, args or {}) for ph, n, ts, dur, _p, _t, args
+            in tele.tracer.events if ph == "X"]
+
+
+def test_runtime_spans_nest_under_engine_step(mux_model):
+    tele = Telemetry()
+    rt = _serve(mux_model, tele)
+    spans = _spans(tele)
+    steps = {a["step"]: (ts, ts + dur) for n, ts, dur, a in spans
+             if n == "engine_step"}
+    assert sorted(steps) == list(range(rt.engine_steps))
+    names = {n for n, *_ in spans}
+    assert {"admit", "cache_edit", "step_inputs", "prefill_chunk",
+            "decode"} <= names
+    for n, ts, dur, a in spans:
+        # every span names its engine step and lies inside it
+        lo, hi = steps[a["step"]]
+        assert lo <= ts and ts + dur <= hi, (n, a)
+    kinds = {a["kind"] for n, *_, a in spans if n == "cache_edit"}
+    assert kinds == {"reset", "tables"}      # no mesh: nothing to commit
+    phases = {a["phase"] for n, *_, a in spans if n == "step_inputs"}
+    assert phases == {"plan", "decode", "chunk"}
+    # admit and prefill_chunk carry the row group's request ids; the
+    # chunks of a row carry the ids that row was admitted with
+    groups = {}
+    for n, _, _, a in spans:
+        if n == "admit":
+            assert a["uids"] and len(a["uids"]) <= 2
+            groups[a["row"]] = a["uids"]
+        elif n == "prefill_chunk":
+            assert a["uids"] == groups[a["row"]]
+    admitted = sorted(u for n, *_, a in spans if n == "admit"
+                      for u in a["uids"])
+    assert admitted == list(range(5))
+
+
+def test_reset_spans_count_reset_calls(mux_model, monkeypatch):
+    from repro.serve import runtime
+    calls = []
+    real = runtime.reset_blocks
+
+    def counted(cache, ids):
+        calls.append(list(ids))
+        return real(cache, ids)
+
+    monkeypatch.setattr(runtime, "reset_blocks", counted)
+    tele = Telemetry()
+    _serve(mux_model, tele)
+    resets = [a for n, *_, a in _spans(tele)
+              if n == "cache_edit" and a["kind"] == "reset"]
+    assert len(resets) == len(calls) > 0
+    assert [a["blocks"] for a in resets] == [len(c) for c in calls]
+
+
+def test_disabled_telemetry_reads_no_clock(mux_model, monkeypatch):
+    import time
+    warm = _serve(mux_model)                 # compile every program first
+    assert warm.tele is NULL_TELEMETRY
+
+    def no_clock():
+        raise AssertionError("a clock was read with telemetry off")
+
+    monkeypatch.setattr(time, "perf_counter", no_clock)
+    monkeypatch.setattr(time, "perf_counter_ns", no_clock)
+    rt = _serve(mux_model, uid0=10)
+    assert len(rt.sched.completed) == 5
+    assert dict(rt.trace_counts) == dict(warm.trace_counts)
