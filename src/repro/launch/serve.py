@@ -1006,6 +1006,16 @@ def main(argv=None):
         compiled = ", ".join(f"{k}×{v}"
                              for k, v in sorted(stats["trace_counts"].items()))
         print(f"compiled programs: {compiled}")
+    hists = (telemetry.registry.snapshot()["histograms"]
+             if telemetry is not None else [])
+    walked = [h for h in hists if h["name"] == "decode_pages_walked_share"]
+    if walked:
+        n = sum(h["count"] for h in walked)
+        print(f"decode pages walked: "
+              f"{sum(h['sum'] for h in walked) / n:.3f} of the table "
+              f"(min {min(h['min'] for h in walked):.3f}, "
+              f"max {max(h['max'] for h in walked):.3f}) over {n} "
+              f"decode steps")
     rec = stats.get("recovery")
     if args.fence_stragglers and rec:
         print(f"stragglers: {rec['stragglers_fenced']} fenced, "

@@ -62,6 +62,17 @@ def blocks_for(num_tokens: int, block_size: int) -> int:
     return -(-max(num_tokens, 0) // block_size)
 
 
+def live_blocks(positions, block_size: int, window=None) -> np.ndarray:
+    """Table blocks that a decode query at each of ``positions`` reads:
+    block j holds positions [j*block_size, (j+1)*block_size)
+    (``paged_write``), so the blocks from the one holding the window's
+    first position to the query's own; 0 for an inactive row (position
+    < 0).  The paged decode kernel walks exactly these."""
+    pos = np.asarray(positions)
+    lo = 0 if window is None else np.maximum(pos - window + 1, 0) // block_size
+    return np.where(pos >= 0, pos // block_size - lo + 1, 0)
+
+
 @dataclass
 class KVPool:
     """Host-side block allocator with per-client block tables.

@@ -67,7 +67,7 @@ from repro.serve import sampling
 from repro.serve.engine import (ServeConfig, init_cache, make_pool, prefill,
                                 prefill_chunk, decode_step, set_block_tables,
                                 reset_blocks, copy_cache_pages)
-from repro.serve.kvpool import PoolExhausted
+from repro.serve.kvpool import PoolExhausted, live_blocks
 from repro.serve.scheduler import ContinuousScheduler
 from repro.serve.telemetry import NULL_SPAN, NULL_TELEMETRY
 
@@ -813,6 +813,13 @@ class ServeRuntime:
             self.stats["decode_steps"] += 1
             self.stats["slot_util"].append(self.sched.utilization())
             self.stats["cache_util"].append(self.pool.utilization())
+            if self.tele.enabled:
+                # share of the table blocks the decode kernel walks
+                mb = self.pool.max_blocks_per_seq
+                walked = live_blocks(pos_vec, self.pool.block_size,
+                                     self.sc.cfg.window).sum()
+                self.tele.observe("decode_pages_walked_share",
+                                  walked / (self.nrows * mb), lane=self.lane)
 
     def _append_slots(self, rows):
         """Reserve each decoding row's next slot; rows whose shard is

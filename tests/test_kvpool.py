@@ -8,8 +8,8 @@ import pytest
 
 from repro.serve.kvpool import (KVPool, ShardedKVPool, PoolError,
                                 PoolExhausted, TRASH_BLOCK, blocks_for,
-                                copy_pages, init_pages, paged_write,
-                                paged_view)
+                                copy_pages, init_pages, live_blocks,
+                                paged_write, paged_view)
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -22,6 +22,14 @@ def test_blocks_for():
     assert blocks_for(1, 4) == 1
     assert blocks_for(4, 4) == 1
     assert blocks_for(5, 4) == 2
+
+
+def test_live_blocks():
+    # first / last slot of a page, an inactive row
+    assert live_blocks([0, 3, 4, 7, -1], 4).tolist() == [1, 1, 2, 2, 0]
+    # a window of 6 ending at 9 covers positions 4..9 (blocks 1-2), at
+    # 11 covers 6..11 (blocks 1-2), at 12 covers 7..12 (blocks 1-3)
+    assert live_blocks([9, 11, 12, 2], 4, window=6).tolist() == [2, 2, 3, 1]
 
 
 def test_alloc_free_roundtrip():

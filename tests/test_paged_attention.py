@@ -15,12 +15,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import quant
 from repro.kernels import ops, ref
 from repro.launch.mesh import make_serve_mesh
 
 KEY = jax.random.PRNGKey(0)
+# the TPU interpreter, for the decode kernel's page DMA: a copy lands only
+# when it is waited on and unwritten VMEM reads as NaN, so a page read
+# before its copy completes, or never fetched, shows in the output (the
+# plain interpreter, which the serve path uses on the CPU, copies at once).
+# Its callbacks dispatch JAX operations, so read each result back before
+# dispatching anything else: a second dispatch meanwhile can deadlock.
+TPU_INTERPRET = pltpu.InterpretParams()
 
 
 def build_pool(lens, *, num_blocks, block_size, max_blocks, hkv, dh, key):
@@ -43,7 +51,8 @@ def build_pool(lens, *, num_blocks, block_size, max_blocks, hkv, dh, key):
     return kp, vp, jnp.asarray(bt), jnp.asarray(ppos)
 
 
-@pytest.mark.parametrize("hkv,window", [(2, None), (2, 12), (8, None)])
+@pytest.mark.parametrize("hkv,window", [(2, None), (2, 12), (8, None),
+                                        (2, 20)])   # window from mid-page
 def test_paged_kernel_matches_ref(hkv, window):
     B, H, DH, BS, MB, P = 3, 8, 16, 8, 6, 16
     q = jax.random.normal(KEY, (B, 1, H, DH))
@@ -210,9 +219,9 @@ def test_paged_kernels_single_row_batch():
 
 
 def test_unallocated_table_entries_stay_masked():
-    """-1 table entries are clamped to page 0 for the gather/DMA; even a
-    'poisoned' page 0 (seemingly valid positions) must not leak into the
-    output, for both the kernel and the reference."""
+    """-1 table entries are clamped to page 0 by the reference's gather
+    (the kernel never fetches them); even a 'poisoned' page 0 (seemingly
+    valid positions) must not leak into the output of either."""
     B, H, HKV, DH, BS, MB, P = 1, 4, 2, 8, 4, 4, 12
     q = jax.random.normal(KEY, (B, 1, H, DH))
     kp, vp, bt, ppos = build_pool([10], num_blocks=P, block_size=BS,
@@ -276,21 +285,29 @@ def _storage_bound(q, kind, kp, vp, scale_kw):
 
 
 @pytest.mark.parametrize("kind", STORE_KINDS)
-@pytest.mark.parametrize("lens,q_pos,mb", [
-    ([37, 12, -1], [36, 11, -1], 6),     # heterogeneous + inactive row
-    ([8, 3, 1], [7, 2, 0], 1),           # whole rows inside ONE block
-    ([29, 13, 7], [28, 12, 6], 4),       # non-power-of-two lengths
+@pytest.mark.parametrize("lens,q_pos,mb,holes", [
+    ([37, 12, -1], [36, 11, -1], 6, []),  # heterogeneous + inactive row
+    ([8, 3, 1], [7, 2, 0], 1, []),        # whole rows inside ONE block
+    ([29, 13, 7], [28, 12, 6], 4, []),    # non-power-of-two lengths
+    # query on the last slot of a page, the first of the next, and the
+    # last again; a row with no live page (q_pos -1) beside them
+    ([8, 9, 16, -1], [7, 8, 15, -1], 3, []),
+    ([32, 32, 5], [31, 31, 4], 4, []),    # full-table rows: every block
+    ([37, 30], [36, 29], 6, [(0, 2), (1, 1)]),  # -1 holes in the range
 ])
-def test_paged_decode_storage_parity(kind, lens, q_pos, mb):
+def test_paged_decode_storage_parity(kind, lens, q_pos, mb, holes):
     B, H, HKV, DH, BS, P = len(lens), 8, 2, 16, 8, 32
     q = jax.random.normal(KEY, (B, 1, H, DH))
     kp, vp, bt, ppos = build_pool(lens, num_blocks=P, block_size=BS,
                                   max_blocks=mb, hkv=HKV, dh=DH,
                                   key=jax.random.fold_in(KEY, mb))
+    for r, j in holes:                    # unallocated mid-range entries
+        bt = bt.at[r, j].set(-1)
     q_pos = jnp.asarray(q_pos, jnp.int32)
     ks, vs, scale_kw, k_hi, v_hi = _stored_pool(kp, vp, kind)
-    got = ops.paged_attention(q, ks, vs, bt, ppos, q_pos,
-                              interpret=True, **scale_kw)
+    got = np.asarray(ops.paged_attention(q, ks, vs, bt, ppos, q_pos,
+                                         interpret=TPU_INTERPRET,
+                                         **scale_kw))
     act = np.asarray(q_pos) >= 0                  # active rows only
     # (a) fused dequant == dequantize-then-attend oracle
     want = (ref.paged_attention_quant_ref(
@@ -305,6 +322,47 @@ def test_paged_decode_storage_parity(kind, lens, q_pos, mb):
     err = np.abs(np.asarray(got)[act] - np.asarray(pristine)[act])
     assert err.max() <= bound, (kind, float(err.max()), bound)
     assert np.isfinite(np.asarray(got)).all()
+
+
+@pytest.mark.parametrize("window", [None, 12])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_paged_decode_dead_pages_never_read(kind, window):
+    """Every page outside every row's live range holds NaN (K/V payload,
+    and the scales of int8 pages) under slot positions that look live:
+    table blocks past a row's query or before its window, and pages no
+    row owns.  The kernel never fetches them, so its output equals the
+    reference on the clean pool and stays finite."""
+    B, H, HKV, DH, BS, MB, P = 4, 8, 2, 16, 8, 6, 32
+    lens, q_pos = [37, 30, 9, -1], [36, 20, 8, -1]
+    q = jax.random.normal(KEY, (B, 1, H, DH))
+    kp, vp, bt, ppos = build_pool(lens, num_blocks=P, block_size=BS,
+                                  max_blocks=MB, hkv=HKV, dh=DH, key=KEY)
+    ks, vs, scale_kw, k_hi, v_hi = _stored_pool(kp, vp, kind)
+    q_pos = jnp.asarray(q_pos, jnp.int32)
+    want = (ref.paged_attention_quant_ref(
+                q, ks, vs, scale_kw["k_scales"], scale_kw["v_scales"],
+                bt, ppos, q_pos, window=window) if scale_kw
+            else ref.paged_attention_ref(q, k_hi, v_hi, bt, ppos, q_pos,
+                                         window=window))
+    live = set()
+    for r, qp in enumerate(np.asarray(q_pos)):
+        if qp >= 0:
+            lo = 0 if window is None else max(0, qp - window + 1) // BS
+            live |= set(np.asarray(bt)[r, lo:qp // BS + 1].tolist())
+    dead = np.array(sorted(set(range(P)) - live))
+    assert len(dead) > P - MB * B                 # some table blocks too
+    ppos = ppos.at[dead].set(jnp.arange(20, 20 + BS))
+    if scale_kw:
+        scale_kw = {k: v.at[dead].set(jnp.nan) for k, v in scale_kw.items()}
+    else:
+        ks, vs = ks.at[dead].set(jnp.nan), vs.at[dead].set(jnp.nan)
+    got = np.asarray(ops.paged_attention(q, ks, vs, bt, ppos, q_pos,
+                                         window=window,
+                                         interpret=TPU_INTERPRET,
+                                         **scale_kw))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[:3], np.asarray(want)[:3],
+                               atol=KERNEL_ATOL, rtol=1e-4)
 
 
 @pytest.mark.parametrize("kind", STORE_KINDS)

@@ -366,3 +366,26 @@ def test_disabled_telemetry_reads_no_clock(mux_model, monkeypatch):
     rt = _serve(mux_model, uid0=10)
     assert len(rt.sched.completed) == 5
     assert dict(rt.trace_counts) == dict(warm.trace_counts)
+
+
+def test_decode_pages_walked_share_per_step(mux_model, monkeypatch):
+    """One observation per decode step: the live table blocks of the
+    step's active rows over rows x table width."""
+    from repro.serve import runtime
+    seen = []
+    real = runtime.live_blocks
+
+    def spy(pos, *a, **kw):
+        seen.append(np.asarray(pos).copy())
+        return real(pos, *a, **kw)
+
+    monkeypatch.setattr(runtime, "live_blocks", spy)
+    tele = Telemetry()
+    rt = _serve(mux_model, tele)
+    h = tele.registry.hist("decode_pages_walked_share", lane=0)
+    assert h.count == len(seen) == rt.stats["decode_steps"] > 0
+    width = CAPACITY // BLOCK
+    want = [np.where(p >= 0, p // BLOCK + 1, 0).sum() / (ROWS * width)
+            for p in seen]
+    assert h.total == pytest.approx(sum(want))
+    assert 0 < h.vmin <= h.vmax <= 1

@@ -66,28 +66,44 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _pool_args(chip, pages):
+def _pool_args(chip, pages, rows=ROWS, mb=MB, bs=BS, pool=POOL, hkv=HKV,
+               dh=DH):
     dt = PAGE_DTYPES[pages]
-    args = [_shape(chip, (POOL, BS, HKV, DH), dt),
-            _shape(chip, (POOL, BS, HKV, DH), dt),
-            _shape(chip, (ROWS, MB), jnp.int32),
-            _shape(chip, (POOL, BS), jnp.int32)]
-    scales = ([_shape(chip, (POOL, BS, HKV), jnp.float32)] * 2
+    args = [_shape(chip, (pool, bs, hkv, dh), dt),
+            _shape(chip, (pool, bs, hkv, dh), dt),
+            _shape(chip, (rows, mb), jnp.int32),
+            _shape(chip, (pool, bs), jnp.int32)]
+    scales = ([_shape(chip, (pool, bs, hkv), jnp.float32)] * 2
               if pages in ("int8", "fp8") else [])
     return args, scales
 
 
-@pytest.mark.parametrize("pages", list(PAGE_DTYPES))
-def test_paged_attention_compiles(chip, pages):
+# (rows, blocks per row, block size, pool blocks, heads, kv heads, head
+# size) and query dtype: the small serve shape above; the benchmark's
+# chat cell (32 rows of 20 x 128-token blocks over 641 pages, bf16),
+# where the page buffers are largest; and h2o-danube-1.8b's heads, whose
+# head size of 80 is not a whole 128-lane tile
+CHAT_POOL = (32, 20, 128, 641, H, HKV, DH)
+DANUBE_POOL = (8, 21, 256, 169, 32, 8, 80)
+
+
+@pytest.mark.parametrize("pages,shape,q_dtype", [
+    *[(p, (ROWS, MB, BS, POOL, H, HKV, DH), jnp.float32)
+      for p in PAGE_DTYPES],
+    ("bf16", CHAT_POOL, jnp.bfloat16), ("int8", CHAT_POOL, jnp.bfloat16),
+    ("bf16", DANUBE_POOL, jnp.bfloat16)])
+def test_paged_attention_compiles(chip, pages, shape, q_dtype):
     from repro.kernels.paged_attention import paged_attention
-    pool, scales = _pool_args(chip, pages)
+    rows, mb, bs, pool_blocks, h, hkv, dh = shape
+    pool, scales = _pool_args(chip, pages, rows, mb, bs, pool_blocks, hkv,
+                              dh)
 
     def f(q, kp, vp, bt, pp, qp, *sc):
         kw = dict(k_scales=sc[0], v_scales=sc[1]) if sc else {}
         return paged_attention(q, kp, vp, bt, pp, qp, **kw)
 
-    c = _compile(f, _shape(chip, (ROWS, 1, H, DH), jnp.float32), *pool,
-                 _shape(chip, (ROWS,), jnp.int32), *scales)
+    c = _compile(f, _shape(chip, (rows, 1, h, dh), q_dtype), *pool,
+                 _shape(chip, (rows,), jnp.int32), *scales)
     assert "tpu_custom_call" in c.as_text()
 
 
