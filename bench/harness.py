@@ -107,21 +107,46 @@ class CompileCounter:
 # the program under test
 # ---------------------------------------------------------------------------
 
+# the program's RMSNorm epsilon where its ModelConfig has no ``norm_eps``
+# (``nn/layers.py`` RMSNorm.apply)
+PROGRAM_NORM_EPS = 1e-6
+
+
 def program_config(conf: dict, spec: dict):
     """The serve path's ModelConfig, MuxSpec and ServeConfig for a
-    configuration file; refuses one whose layer equations the reference
-    does not share."""
+    configuration file; refuses one that the program would not run as
+    the reference computes it.
+
+    Of ``model_spec``'s keys, passed on to the program: ``layers``,
+    ``d``, ``heads``, ``kv_heads``, ``head_dim``, ``ffn``, ``vocab``,
+    ``qkv_bias``, ``tied``, ``rope_theta`` and ``window`` (ModelConfig),
+    ``n_mux`` and ``demux_hidden`` (MuxSpec), and ``norm_eps`` as
+    ``ModelConfig.norm_eps`` where the program's ModelConfig has that
+    field.  Checked instead: ``norm_eps`` where it has not, which has
+    to be the program's fixed ``PROGRAM_NORM_EPS``.  The layer
+    equations (activation, gated FFN, RMSNorm, RoPE, causal, no soft
+    cap, no embedding scale, attention blocks only, no experts) come
+    from the architecture and are checked against the reference's."""
+    import dataclasses
     import jax.numpy as jnp
     from repro.configs import get_config
     from repro.core import MuxSpec
     from repro.serve import ServeConfig
     base = get_config(conf["arch"])
+    extra = {}
+    if "norm_eps" in {f.name for f in dataclasses.fields(base)}:
+        extra["norm_eps"] = spec["norm_eps"]
+    elif spec["norm_eps"] != PROGRAM_NORM_EPS:
+        raise ValueError(
+            f"{conf['arch']}: rms_norm_eps is {spec['norm_eps']!r} in the "
+            f"configuration, but the program's RMSNorm epsilon is fixed at "
+            f"{PROGRAM_NORM_EPS!r} (its ModelConfig has no norm_eps)")
     cfg = base.replace(
         n_layers=spec["layers"], d_model=spec["d"], n_heads=spec["heads"],
         n_kv_heads=spec["kv_heads"], head_dim=spec["head_dim"],
         d_ff=spec["ffn"], vocab_size=spec["vocab"], qkv_bias=spec["qkv_bias"],
         tie_embeddings=spec["tied"], rope_theta=spec["rope_theta"],
-        window=spec["window"])
+        window=spec["window"], **extra)
     same = (cfg.activation == conf["hidden_act"] and cfg.glu
             and cfg.norm == "rms" and cfg.positions == "rope" and cfg.causal
             and cfg.logit_softcap is None and not cfg.embedding_scale
@@ -130,8 +155,9 @@ def program_config(conf: dict, spec: dict):
         raise ValueError(f"{conf['arch']}: layer equations differ from the "
                          "reference (bench/reference/decoder.py)")
     mux = conf["mux"]
-    mspec = MuxSpec(n=mux["n"], mux_kind=mux["mux_kind"],
-                    demux_kind=mux["demux_kind"])
+    mspec = MuxSpec(n=spec["n_mux"], mux_kind=mux["mux_kind"],
+                    demux_kind=mux["demux_kind"],
+                    demux_hidden=spec["demux_hidden"])
     srv = conf["serve"]
     sc = ServeConfig(cfg=cfg, kind="lm", mux=mspec,
                      capacity=srv["capacity"],
@@ -431,6 +457,14 @@ def telemetry_spans(tele, clock_offset: float, lo: float, hi: float):
     return out
 
 
+def reduce_trace(path) -> dict:
+    """What the per-layer readers see of the profiler trace at ``path``:
+    ``bench/trace_reduce.py``'s keys and ``bench/trace_spans.py``'s
+    (``idle_by_span``, ``modules``)."""
+    from bench import trace_reduce, trace_spans
+    return {**trace_reduce.reduce(path), **trace_spans.reduce(path)}
+
+
 def peaks_for(kind: str) -> dict:
     table = json.loads((ROOT / "bench" / "peaks.json").read_text())
     if kind not in table["devices"]:
@@ -581,7 +615,6 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
               "count": st.n_devices, "memory_peak_bytes": peak}
     breakdown = None
     if trace:
-        from bench.trace_reduce import reduce as reduce_trace
         tele = st.tele
         offset = (time.perf_counter() - tele.tracer.now_us() / 1e6
                   - drv.t_origin)

@@ -3,8 +3,11 @@
 
 A tiny cell keeps a cell's configuration and traffic files but shrinks
 every size so that a whole run, kernels in interpret mode included,
-takes seconds on the CPU."""
+takes seconds on the CPU.  The cells are every workload of
+``BENCHMARK.json``, each configuration found through its ``file`` there,
+and the test-only ``VARIANTS``."""
 import copy
+import json
 import pathlib
 import sys
 
@@ -14,24 +17,27 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-# test-only variants of a configuration file: the layer features that the
-# reference carries beside qwen2's (a sliding window, an untied head, no
-# q/k/v bias), as the program's h2o-danube-1.8b has them
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+CONFIG_FILES = {c["name"]: c["file"] for c in BENCH["configs"]}
+# test-only variants of a configuration: (configuration, traffic mix,
+# changed keys).  "windowed-untied" carries the layer features of the
+# reference beside qwen2's (a sliding window, an untied head, no q/k/v
+# bias), as the program's h2o-danube-1.8b has them
 VARIANTS = {
-    "windowed-untied": ("qwen2-1.5b-mux2", {
+    "windowed-untied": ("qwen2-1.5b-mux2", "chat", {
         "arch": "h2o-danube-1.8b", "qkv_bias": False,
         "tie_word_embeddings": False, "use_sliding_window": True,
         "sliding_window": 4096, "rope_theta": 10000.0}),
 }
 # (configuration, traffic mix) of the tiny cells
-CELLS = (("qwen2-1.5b-mux2", "chat"), ("windowed-untied", "chat"))
+CELLS = tuple((w["config"], w["traffic"]) for w in BENCH["workloads"]) + \
+    tuple((name, mix) for name, (_, mix, _) in VARIANTS.items())
 
 
 def cell(config: str, mix: str) -> dict:
     from bench import harness
-    base, changes = VARIANTS.get(config, (config, {}))
-    c = harness.cell_from_files(f"{config}.{mix}",
-                                f"bench/configs/{base}.json", mix)
+    base, _, changes = VARIANTS.get(config, (config, mix, {}))
+    c = harness.cell_from_files(f"{config}.{mix}", CONFIG_FILES[base], mix)
     c["config"].update(changes)
     return c
 

@@ -1,7 +1,8 @@
 """The reduction's two added keys (``bench/trace_spans.py``): device idle
 put down to the innermost program span, and device time per step
-program from the module line; and the five readers built on them, on
-events laid out by hand (times in ns)."""
+program from the module line, as ``harness.reduce_trace`` merges them
+for the readers; and the five readers built on them, on events laid
+out by hand (times in ns) and on a recorded chip trace."""
 import json
 
 import numpy as np
@@ -30,7 +31,7 @@ def red(monkeypatch):
                         lambda path: (DEVICE, {"fusion.1": 300e-9},
                                       {"fusion.1": ""}, HOST))
     monkeypatch.setattr(trace_spans, "load_modules", lambda path: MODS)
-    return {**trace_reduce.reduce("unused"), **trace_spans.reduce("unused")}
+    return harness.reduce_trace("unused")
 
 
 def test_idle_by_span_innermost_and_complete(red):
@@ -136,8 +137,7 @@ def recorded(monkeypatch):
     monkeypatch.setattr(trace_reduce, "load",
                         lambda path: (devices, {}, {}, host))
     monkeypatch.setattr(trace_spans, "load_modules", lambda path: mods)
-    return host, {**trace_reduce.reduce("unused"),
-                  **trace_spans.reduce("unused")}
+    return host, harness.reduce_trace("unused")
 
 
 def test_recorded_step_trace_idle_by_span(recorded):
@@ -180,3 +180,26 @@ def test_recorded_step_trace_modules(recorded):
                   if n.startswith("jit__decode_impl"))
     for (s, e), (hs, he) in zip(runs, decode):
         assert hs < e <= he
+
+
+def test_declared_readers_read_the_recorded_trace(recorded):
+    # the five readers that BENCHMARK.json declares for the chat cell, on
+    # what run_cell hands them (harness.reduce_trace) of the recorded trace
+    host, red = recorded
+    declared = [m["name"] for m in json.loads(
+        (harness.ROOT / "BENCHMARK.json").read_text())["per_layer"]
+        if m["name"] in NEW]
+    assert sorted(declared) == sorted(NEW)
+    spans = [(n, s * 1e-9, (e - s) * 1e-9, {}) for s, e, n in host]
+    got = harness.read_per_layer(declared, _view(red, spans))
+    assert got["decode_device_ms"]["value"] == pytest.approx(
+        1e3 * 0.186238364 / 4)
+    assert got["prefill_chunk_device_ms"]["value"] == pytest.approx(
+        1e3 * 0.172910937 / 5)
+    assert got["idle_share.cache_edit"]["value"] == pytest.approx(
+        100 * 0.006719098 / 0.388296708)
+    assert got["idle_share.step_inputs"]["value"] == pytest.approx(
+        100 * 0.003943454 / 0.388296708)
+    names = [n for _, _, n in host]
+    assert got["cache_edits_per_step"]["value"] == pytest.approx(
+        names.count("cache_edit") / names.count("engine_step"))
