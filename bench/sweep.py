@@ -5,8 +5,11 @@ growing backlog.
 
 One process sets the cell up once, then serves the cell's traffic at
 each rate in turn (its warm-up, then a window), and drains what is left
-before the next rate.  Per rate it prints one JSON line: the offered and
-admitted rates, the backlog (requests due and not yet admitted) at the
+before the next rate.  Rate i's traffic comes from seed ``--seed`` + i;
+in a mix with ``order_seed`` that seed draws only the token ids, so the
+sweep serves one fixed order at every rate, laid over the number of
+requests the rate makes.  Per rate it prints one JSON line: the offered
+and admitted rates, the backlog (requests due and not yet admitted) at the
 window's start and end, completed tokens per second, and TTFT and
 inter-token-gap percentiles.  A backlog that grows across the window,
 or an admitted rate below the offered, marks a rate above the knee:

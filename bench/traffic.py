@@ -1,23 +1,33 @@
 """One general generator for every traffic mix (``bench/traffic/<mix>.json``).
 
 A mix file gives the arrival process, the rate in requests per second,
-the warm-up length, and the prompt and output length distributions.
-``make_schedule`` turns it into the requests of one run: the warm-up
-segment, then the measured window.
+the warm-up length, and the prompt and output length distributions,
+and optionally ``order_seed``.  ``make_schedule`` turns it into the
+requests of one run: the warm-up segment, then the measured window.
 
 So that seeds change the order of the work and not the work itself,
 each segment's sizes are fixed by the mix: a segment of D seconds at
 rate r holds round(r * D) requests whose inter-arrival gaps are the
 stratified quantiles of the exponential distribution (rescaled to sum
 to D) and whose lengths are the stratified quantiles of their lognormal
-distributions, clipped to their bounds.  The seed orders gaps, prompt
-lengths and output lengths independently, in a balanced order: the
+distributions, clipped to their bounds.  Gaps, prompt lengths and
+output lengths are ordered independently, in a balanced order: the
 sorted values fall into ``BANDS`` equal bands, and each run of
 ``BANDS`` consecutive requests takes one value of every band, so that
-every few seconds of a segment carry the same mix whatever the seed
+every few seconds of a segment carry the same mix whatever the order
 (a plain shuffle let one seed put its longest outputs at the window's
 end, where they count only in part).  Every token id is drawn uniformly
 from [1, vocab) (id 0 is the serve path's pad).
+
+Who chooses the order: in a mix without ``order_seed`` the run's seed
+does, so every seed serves the same work in another order.  In a mix
+with ``order_seed`` (an int) that seed does, and every run's seed
+serves the same schedule (due times, prompt lengths, output lengths)
+and draws only the prompts' token ids.  That is for mixes with few
+long requests in a window, where which request lands where, and which
+requests share a row, moves the throughput more than the run-to-run
+noise of the chip does.  A ``rate`` override keeps the fixed order,
+laid over the number of requests the new rate makes.
 """
 from __future__ import annotations
 
@@ -75,15 +85,17 @@ def balanced_order(rng, values, bands: int = BANDS) -> np.ndarray:
     return np.asarray(out, dtype=np.asarray(values).dtype)
 
 
-def _segment(mix, rng, start, duration, vocab):
+def _segment(mix, order, ids, start, duration, vocab):
+    """One segment: its order from ``order``, token ids from ``ids``
+    (the same generator where the run's seed chooses the order)."""
     m = int(round(mix["rate_rps"] * duration))
     if m == 0:
         return np.zeros(0), [], np.zeros(0, np.int64)
-    gaps = balanced_order(rng, gap_quantiles(mix["rate_rps"], duration, m))
+    gaps = balanced_order(order, gap_quantiles(mix["rate_rps"], duration, m))
     due = start + np.concatenate([[0.0], np.cumsum(gaps[:-1])])
-    plen = balanced_order(rng, length_quantiles(mix["prompt_len"], m))
-    olen = balanced_order(rng, length_quantiles(mix["output_len"], m))
-    prompts = [rng.integers(1, vocab, size=int(n), dtype=np.int32)
+    plen = balanced_order(order, length_quantiles(mix["prompt_len"], m))
+    olen = balanced_order(order, length_quantiles(mix["output_len"], m))
+    prompts = [ids.integers(1, vocab, size=int(n), dtype=np.int32)
                for n in plen]
     return due, prompts, olen
 
@@ -92,15 +104,21 @@ def make_schedule(mix: dict, seed: int, seconds: float, vocab: int,
                   rate: float | None = None) -> Schedule:
     """The requests of one run: ``mix['warmup_s']`` seconds of warm-up,
     then ``seconds`` of window.  ``rate`` overrides the mix's rate (the
-    knee sweep)."""
+    knee sweep).  The order comes from ``mix['order_seed']`` where the
+    mix has one, else from ``seed``; token ids always from ``seed``."""
     if mix.get("arrivals", "poisson") not in ARRIVALS:
         raise ValueError(f"unknown arrival process {mix.get('arrivals')!r}")
     if rate is not None:
         mix = {**mix, "rate_rps": rate}
-    rng = np.random.default_rng(seed)
+    order_seed = mix.get("order_seed")
+    if order_seed is not None and (isinstance(order_seed, bool)
+                                   or not isinstance(order_seed, int)):
+        raise ValueError(f"order_seed must be an int, not {order_seed!r}")
+    ids = np.random.default_rng(seed)
+    order = ids if order_seed is None else np.random.default_rng(order_seed)
     warm = float(mix["warmup_s"])
-    parts = [_segment(mix, rng, 0.0, warm, vocab),
-             _segment(mix, rng, warm, float(seconds), vocab)]
+    parts = [_segment(mix, order, ids, 0.0, warm, vocab),
+             _segment(mix, order, ids, warm, float(seconds), vocab)]
     return Schedule(due=np.concatenate([p[0] for p in parts]),
                     prompts=[x for p in parts for x in p[1]],
                     max_new=np.concatenate([p[2] for p in parts]),
@@ -112,8 +130,10 @@ def mean_length(dist: dict, m: int = 4096) -> float:
 
 
 def describe(mix: dict) -> str:
+    order = ("" if mix.get("order_seed") is None
+             else f", order seed {mix['order_seed']}")
     return (f"{mix.get('arrivals', 'poisson')} {mix['rate_rps']} req/s, "
             f"prompts ~{mean_length(mix['prompt_len']):.0f} tokens, "
             f"outputs ~{mean_length(mix['output_len']):.0f} tokens "
-            f"(means), warm-up {mix['warmup_s']} s")
+            f"(means), warm-up {mix['warmup_s']} s{order}")
 
